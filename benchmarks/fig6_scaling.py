@@ -21,8 +21,10 @@ the compression trajectory is structural, not a wall-clock claim.
 safe narrowing, so the config-time raise is asserted instead (the
 ``--smoke`` CI run keeps that gate exercised).
 
-Re-execs itself with a forced host device count so the multi-device
-run never contaminates the parent process's single-device state.
+Re-execs itself on CPU (``JAX_PLATFORMS=cpu``) with a forced host
+device count: the virtual devices exist only on the CPU backend, and
+the child must not take a chip the parent may hold.  Every row and the
+JSON name the platform the child measured (``cpu``).
 ``--smoke``: a small-graph, two-mesh subset for the benchmark-smoke CI
 job.
 """
@@ -41,6 +43,7 @@ WIRE_CODECS = ["identity", "delta", "bitmap"]
 
 def run(smoke: bool = False):
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count="
                           f"{MAX_DEV}").strip()
@@ -84,6 +87,7 @@ def _comm_rows(gluon, sg, mesh, src, cfg_base, sync, meta, max_rounds):
 def inner(smoke: bool = False):
     import json
     import time
+    import jax
     from repro.core import graph as G
     from repro.core.partition import partition
     from repro.core import gluon
@@ -96,6 +100,7 @@ def inner(smoke: bool = False):
     dev_counts = [2, 4] if smoke else [1, 2, 4, 8]
     strategies = ["alb"] if smoke else ["twc", "alb"]
     max_rounds = 200
+    platform = jax.devices()[0].platform
 
     # config-time gate: quantize on sssp (no declared narrowing) must
     # refuse to run — keep that contract exercised wherever fig6 runs
@@ -141,7 +146,7 @@ def inner(smoke: bool = False):
                          secs,
                          f"rounds={rounds};"
                          f"bytes_total={c['bytes_synced_total']};"
-                         f"ht={ht}")
+                         f"ht={ht};platform={platform}")
                     rows.append(dict(
                         app="sssp", strategy=strat, num_devices=ndev,
                         sync=sync, mode=mode, wire="identity",
@@ -163,6 +168,7 @@ def inner(smoke: bool = False):
         json.dump(dict(
             figure="fig6_scaling",
             smoke=smoke,
+            platform=platform,
             graph=dict(kind="rmat", scale=scale, edge_factor=ef,
                        num_vertices=g.num_vertices,
                        num_edges=g.num_edges),

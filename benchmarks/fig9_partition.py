@@ -1,7 +1,10 @@
 """Fig 9 analogue: ALB under different partition policies (IEC / OEC /
 CVC) — the paper's point: whatever the partitioner does about
 inter-device balance, intra-device thread-block imbalance remains and
-ALB fixes it."""
+ALB fixes it.
+
+Re-execs itself on CPU (``JAX_PLATFORMS=cpu``) with four forced host
+devices; every row names that platform."""
 from __future__ import annotations
 
 import os
@@ -13,6 +16,7 @@ NDEV = 4
 
 def run():
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count="
                           f"{NDEV}").strip()
@@ -29,6 +33,7 @@ def run():
 
 def inner():
     import time
+    import jax
     from repro.core import graph as G
     from repro.core.partition import partition, partition_stats
     from repro.core import gluon
@@ -52,7 +57,8 @@ def inner():
                 secs = time.perf_counter() - t0
                 emit(f"fig9/sssp/{policy}/{strat}/{sync}", secs,
                      f"edge_imbalance={st['imbalance']:.2f};"
-                     f"replication={st['replication_factor']:.2f}")
+                     f"replication={st['replication_factor']:.2f};"
+                     f"platform={jax.devices()[0].platform}")
 
 
 if __name__ == "__main__":

@@ -81,6 +81,8 @@ def main() -> None:
               f"(see --help)", file=sys.stderr)
         sys.exit(2)
     which = set(argv) or set(SELECTORS)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     if "table2" in which:
         from . import table2_strategies

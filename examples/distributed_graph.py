@@ -1,7 +1,9 @@
 """Multi-device graph analytics: CuSP-analog partitioning + Gluon-analog
 BSP sync, the paper's D-IrGL(ALB) system (Sections 5/6.2).
 
-Re-execs itself with 4 forced host devices (CPU stand-ins for GPUs).
+Re-execs itself on CPU with 4 forced host devices (CPU stand-ins for
+GPUs); on a TPU host, ``python chip_smoke.py --chips 4`` runs the same
+partitioned path on four chips.
 
   PYTHONPATH=src python examples/distributed_graph.py
 """
@@ -11,6 +13,7 @@ import sys
 
 if os.environ.get("_REPRO_INNER") != "1":
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=4")
     env["_REPRO_INNER"] = "1"
@@ -19,15 +22,19 @@ if os.environ.get("_REPRO_INNER") != "1":
 import numpy as np
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import graph as G
 from repro.core.partition import partition, partition_stats
 from repro.core import gluon
 from repro.core.balancer import BalancerConfig
 from repro.core.apps import sssp
 
+enable_compile_cache()
+
 g = G.rmat(12, 16, seed=0)
 src = G.highest_out_degree_vertex(g)
-print(f"devices: {len(jax.devices())}; graph |V|={g.num_vertices} "
+print(f"devices: {len(jax.devices())} {jax.devices()[0].platform}; "
+      f"graph |V|={g.num_vertices} "
       f"|E|={g.num_edges}")
 
 ref = sssp(g, src, BalancerConfig(strategy="alb", threshold=1024))

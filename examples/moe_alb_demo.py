@@ -17,8 +17,11 @@ import jax                                          # noqa: E402
 import jax.numpy as jnp                             # noqa: E402
 import numpy as np                                  # noqa: E402
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.configs.base import ModelConfig, MoEConfig  # noqa: E402
 from repro.models import moe as MOE                 # noqa: E402
+
+enable_compile_cache()
 
 
 def cfg_with(adaptive):

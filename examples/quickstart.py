@@ -8,9 +8,12 @@ exists.
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import graph as G
 from repro.core.balancer import BalancerConfig
 from repro.core.apps import sssp
+
+enable_compile_cache()
 
 # power-law graph (rmat): a few vertices own most edges
 g = G.rmat(scale=12, edge_factor=16, seed=0)

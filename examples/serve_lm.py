@@ -12,8 +12,11 @@ sys.path.insert(0, "src")
 import jax                                     # noqa: E402
 import jax.numpy as jnp                        # noqa: E402
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.configs import get_smoke_config     # noqa: E402
 from repro.models import transformer as T     # noqa: E402
+
+enable_compile_cache()
 
 B, PROMPT, GEN = 4, 48, 16
 

@@ -10,6 +10,7 @@ import tempfile
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.configs import get_config          # noqa: E402
 from repro.launch.train import main as train  # noqa: E402
 import repro.configs.llama3_8b as l3          # noqa: E402
@@ -29,6 +30,7 @@ if __name__ == "__main__":
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=256)
     args = ap.parse_args()
+    enable_compile_cache()
 
     # register the 100M config in place of the smoke config
     import repro.launch.train as TR

@@ -87,6 +87,7 @@ import numpy as np
 
 from .graph import Graph
 from .frontier import (next_bucket, compact, count, dirty_mask,
+                       prefix_sum, scatter_rows, spread_index,
                        union_frontier)
 from .operators import Operator, as_pull
 
@@ -193,12 +194,12 @@ class RoundPlan:
     lb: str
     direction: str = "push"
 
-    def lb_mask(self, deg, valid, cfg: BalancerConfig):
+    def lb_mask(self, deg, valid, threshold: int):
         """Which frontier vertices the edge-balanced path serves."""
         if self.lb == "all":
             return valid & (deg > 0)
         if self.lb == "huge":
-            return valid & (deg >= cfg.threshold)
+            return valid & (deg >= threshold)
         raise ValueError(self.lb)
 
 
@@ -498,10 +499,11 @@ def _apply(labels, target, cand, emask, live, combine):
     cand = cand.astype(labels.dtype)
     if combine == "min":
         cand = jnp.where(full, cand, combine_neutral("min", labels.dtype))
-        return labels.at[:, tgt].min(cand, mode="drop")
-    if combine == "add":
-        return labels.at[:, tgt].add(jnp.where(full, cand, 0), mode="drop")
-    raise ValueError(combine)
+    elif combine == "add":
+        cand = jnp.where(full, cand, 0)
+    else:
+        raise ValueError(combine)
+    return scatter_rows(labels, tgt, cand, combine)
 
 
 def _bin_pass_impl(g: Graph, values, labels, fmask, vidx, deg, row_start,
@@ -519,7 +521,8 @@ def _bin_pass_impl(g: Graph, values, labels, fmask, vidx, deg, row_start,
     base = jnp.asarray(chunk, jnp.int32) * width
     off = base + jnp.arange(width, dtype=jnp.int32)[None, :]      # [1,W]
     emask = off < deg[:, None]                                     # [N,W]
-    graph_e = jnp.where(emask, row_start[:, None] + off, 0)
+    graph_e = spread_index(emask, row_start[:, None] + off,
+                           g.col_idx.shape[0])
     dst = g.col_idx[graph_e]
     w = g.edge_w[graph_e]
     vsafe = jnp.where(vidx < v, vidx, 0)
@@ -542,6 +545,21 @@ def _bin_pass_impl(g: Graph, values, labels, fmask, vidx, deg, row_start,
 _bin_pass = partial(jax.jit, static_argnames=("width", "op"))(_bin_pass_impl)
 
 
+def _segment_values(starts, vals, n: int):
+    """``vals[j(i)]`` for every ``i`` in ``[0, n)``, ``j(i)`` the last
+    ``k`` with ``starts[k] <= i`` (``starts`` non-decreasing, from 0) —
+    ``vals[searchsorted(starts, i, "right") - 1]`` without a search:
+    each slot adds its step ``vals[k] - vals[k-1]`` at its start and a
+    prefix sum carries it forward.  Equal starts (zero-length slots)
+    telescope to the last of them, as the search picks; integer
+    wrap-around cannot change a telescoped sum."""
+    step = vals - jnp.concatenate([jnp.zeros((1,), vals.dtype),
+                                   vals[:-1]])
+    acc = scatter_rows(jnp.zeros((1, n), vals.dtype), starts, step[None],
+                       "add")[0]
+    return prefix_sum(acc)
+
+
 def _lb_pass_impl(g: Graph, values, labels, fmask, hidx, hdeg, hrow_start,
                   total_edges, ecap: int, op: Operator,
                   distribution: str, num_tiles: int, tile_edges: int = 0):
@@ -549,8 +567,12 @@ def _lb_pass_impl(g: Graph, values, labels, fmask, hidx, hdeg, hrow_start,
 
     Edges of the huge vertices get global ids 0..total_edges-1 via an
     exclusive prefix sum over their degrees; each edge id is mapped back
-    to (src, graph edge) by binary search (searchsorted) in that prefix
-    array — the paper's CSR-preserving trick.  ``distribution`` controls
+    to (src, graph edge) by the slot whose prefix-sum entry is the last
+    one at or below it — the paper's CSR-preserving trick.  The paper
+    finds that slot by binary search; here every id's slot values come
+    from one scatter of the slots' steps and a prefix sum
+    (:func:`_segment_values`), since a TPU runs each of a search's
+    ~log2(H) gather levels over every id.  ``distribution`` controls
     the edge-id -> lane order (cyclic = consecutive lanes process
     consecutive edges; blocked = strided) — Section 4.1 / Figure 4.
     ``tile_edges`` is unused here (XLA has no grid); kept for executor
@@ -561,26 +583,26 @@ def _lb_pass_impl(g: Graph, values, labels, fmask, hidx, hdeg, hrow_start,
     the edge's source is actually active in (DESIGN.md section 7).
     """
     v = labels.shape[-1]
-    start_e = jnp.cumsum(hdeg) - hdeg                  # exclusive prefix
+    start_e = prefix_sum(hdeg) - hdeg                  # exclusive prefix
     # enumerate a multiple of num_tiles so the blocked permutation below
     # is a bijection of [0, n_enum) and cannot miss edges
     w_per = -(-ecap // num_tiles)
     n_enum = w_per * num_tiles
     eid = jnp.arange(n_enum, dtype=jnp.int32)
+    # per id in natural order: graph edge = id + (row start - prefix
+    # start) of its slot, and the slot's vertex
+    graph_e = _segment_values(start_e, hrow_start - start_e, n_enum) + eid
+    src = _segment_values(start_e, hidx, n_enum)
     if distribution == "blocked":
         # thread T_i gets the contiguous chunk [i*w_per, (i+1)*w_per):
         # lane-major order becomes strided by w_per (Figure 4 right).
         eid = (eid % num_tiles) * w_per + eid // num_tiles
+        graph_e, src = graph_e[eid], src[eid]
     emask = eid < total_edges
-    eid_c = jnp.where(emask, eid, 0)
-    j = jnp.searchsorted(start_e, eid_c, side="right") - 1   # src slot
-    j = jnp.clip(j, 0, hidx.shape[0] - 1)
-    graph_e = hrow_start[j] + (eid_c - start_e[j])
-    graph_e = jnp.where(emask, graph_e, 0)
-    src = hidx[j]
+    graph_e = spread_index(emask, graph_e, g.col_idx.shape[0])
     dst = g.col_idx[graph_e]
     w = g.edge_w[graph_e]
-    ssafe = jnp.where(src < v, src, 0)
+    ssafe = spread_index(emask & (src < v), src, v)
     if op.direction == "push":
         live = fmask[:, ssafe]                         # [B, n_enum]
         cand = op.msg(values[:, ssafe], w[None])
@@ -698,7 +720,7 @@ def _host_round_counts(g: Graph, frontier: jax.Array, cfg: BalancerConfig):
         md = jnp.where(m, deg, 0)
         vals += [jnp.sum(m.astype(jnp.int32)), jnp.max(md), jnp.sum(md)]
     if plan.lb != "none":
-        hm = plan.lb_mask(deg, union, cfg)
+        hm = plan.lb_mask(deg, union, cfg.threshold)
         vals += [jnp.sum(hm.astype(jnp.int32)),
                  jnp.sum(jnp.where(hm, deg, 0))]
     head = jnp.stack([jnp.asarray(v, jnp.int32) for v in vals])
@@ -751,6 +773,17 @@ def _pull_plan_key(cfg: BalancerConfig) -> tuple:
             cfg.executor == "merge_path")
 
 
+@partial(jax.jit, static_argnames=("plan", "threshold"))
+def _plan_masks(deg, valid, plan: RoundPlan, threshold: int):
+    """Every bin's membership mask over a compacted frontier, then the
+    LB path's (when the plan has one): one program per (plan, frontier
+    bucket) in place of a few eager element-wise dispatches per bin."""
+    masks = tuple(spec.mask(deg, valid) for spec in plan.bins)
+    if plan.lb != "none":
+        masks += (plan.lb_mask(deg, valid, threshold),)
+    return masks
+
+
 def _assemble_bins(cnt: np.ndarray, plan: RoundPlan,
                    cfg: BalancerConfig, fidx, deg, row_start, valid,
                    fcap: int, v: int):
@@ -762,13 +795,13 @@ def _assemble_bins(cnt: np.ndarray, plan: RoundPlan,
     enumeration (once per graph), so the count layout can never
     desynchronize between them."""
     bins, k = [], 1
-    for spec in plan.bins:
+    masks = _plan_masks(deg, valid, plan, cfg.threshold)
+    for spec, mask in zip(plan.bins, masks):
         n, max_d, edge_sum = int(cnt[k]), int(cnt[k + 1]), int(cnt[k + 2])
         k += 3
         if n == 0:
             bins.append(None)
             continue
-        mask = spec.mask(deg, valid)
         bvidx, bdeg, brow = _gather_bin(mask, fidx, deg, row_start,
                                         next_bucket(n), fcap, v)
         bins.append((max_d, edge_sum, bvidx, bdeg, brow))
@@ -777,8 +810,7 @@ def _assemble_bins(cnt: np.ndarray, plan: RoundPlan,
         # ---- inspector (Section 4.1): is the huge bin non-empty? ----
         n_huge, total = int(cnt[k]), int(cnt[k + 1])
         if n_huge > 0 and total > 0:
-            hmask = plan.lb_mask(deg, valid, cfg)
-            hvidx, hdeg, hrow = _gather_bin(hmask, fidx, deg, row_start,
+            hvidx, hdeg, hrow = _gather_bin(masks[-1], fidx, deg, row_start,
                                             next_bucket(n_huge), fcap, v)
             lb = (total, hvidx, hdeg, hrow)
     return tuple(bins), lb
@@ -1029,7 +1061,7 @@ def _relax_spmd_impl(g: Graph, values: jax.Array, labels: jax.Array,
     lb_invoked = jnp.asarray(False)
     tl_lb = jnp.zeros((cfg.num_tiles,), jnp.int32)
     if plan.lb != "none":
-        hmask = plan.lb_mask(deg, valid, cfg)
+        hmask = plan.lb_mask(deg, valid, cfg.threshold)
         n_huge = jnp.sum(hmask.astype(jnp.int32))
         ecap = g.col_idx.shape[0]
         hvidx = jnp.where(hmask, fidx, v)

@@ -50,12 +50,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from .graph import Graph, INF
 from .balancer import (BalancerConfig, RoundStats, RoundStatsDev,
                        relax_spmd, combine_neutral, _note_host_transfer)
-from .frontier import multi_source_state
+from .frontier import multi_source_state, scatter_rows
 from .operators import Operator
 from .partition import PartitionMeta
 from . import operators as ops
@@ -66,9 +65,15 @@ from .wire import step_logical_bytes
 def device_mesh(num_devices: int | None = None):
     """A 1-D ``("dev",)`` mesh over the first ``num_devices`` local
     devices (all of them by default) — what every distributed driver
-    here expects."""
+    here expects.  Raises ``ValueError`` when fewer than
+    ``num_devices`` devices exist: a smaller mesh would run the
+    partitions on fewer devices than the caller asked for."""
     devs = jax.devices()
     if num_devices is not None:
+        if num_devices > len(devs):
+            raise ValueError(f"device_mesh({num_devices}) needs "
+                             f"{num_devices} devices; {len(devs)} "
+                             f"{devs[0].platform} device(s) exist")
         devs = devs[:num_devices]
     return jax.sharding.Mesh(np.asarray(devs), ("dev",))
 
@@ -136,10 +141,10 @@ def make_round_fn(mesh, cfg: BalancerConfig, op: Operator,
     if collect_stats:
         out_specs = (P(), RoundStatsDev(
             *([P("dev")] * len(RoundStatsDev._fields))))
-    fn = shard_map(round_fn, mesh=mesh,
-                   in_specs=(gspec, P(), P(), P()),
-                   out_specs=out_specs,
-                   check_rep=False)
+    fn = jax.shard_map(round_fn, mesh=mesh,
+                       in_specs=(gspec, P(), P(), P()),
+                       out_specs=out_specs,
+                       check_vma=False)
     return jax.jit(fn)
 
 
@@ -185,10 +190,10 @@ def make_fused_traversal_fn(mesh, cfg: BalancerConfig, op: Operator,
         return labels, r
 
     gspec = Graph(row_ptr=P("dev"), col_idx=P("dev"), edge_w=P("dev"))
-    fn = shard_map(trav_fn, mesh=mesh,
-                   in_specs=(gspec, P(), P()),
-                   out_specs=(P(), P()),
-                   check_rep=False)
+    fn = jax.shard_map(trav_fn, mesh=mesh,
+                       in_specs=(gspec, P(), P()),
+                       out_specs=(P(), P()),
+                       check_vma=False)
     return jax.jit(fn)
 
 
@@ -323,10 +328,7 @@ def make_mirror_round_fn(mesh, cfg: BalancerConfig, op: Operator,
                 safe_in = jnp.where(in_idx < v, in_idx, 0)
                 recv = codec.decode(recv, prev_reduce[:, safe_in], op,
                                     new.dtype)
-                if op.combine == "min":
-                    acc = acc.at[:, in_idx].min(recv, mode="drop")
-                else:
-                    acc = acc.at[:, in_idx].add(recv, mode="drop")
+                acc = scatter_rows(acc, in_idx, recv, op.combine)
 
             if global_of is not None:
                 ovids = jnp.arange(v, dtype=jnp.int32)
@@ -365,7 +367,7 @@ def make_mirror_round_fn(mesh, cfg: BalancerConfig, op: Operator,
                 # (kcore degrees in [2^15, 2^16) stay exact)
                 recv = codec.decode(recv, labels[:, safe_in], op,
                                     final.dtype, signed=False)
-                final = final.at[:, in_idx].set(recv, mode="drop")
+                final = scatter_rows(final, in_idx, recv, "set")
 
             new_frontier = next_frontier(labels, final, frontier)
             active = jax.lax.psum(
@@ -428,11 +430,11 @@ def make_mirror_round_fn(mesh, cfg: BalancerConfig, op: Operator,
         if collect_stats:
             out_specs += (RoundStatsDev(
                 *([P("dev")] * len(RoundStatsDev._fields))),)
-    fn = shard_map(round_fn, mesh=mesh,
-                   in_specs=(gspec, P("dev"), P("dev"), P("dev"), P("dev"),
-                             P("dev"), P("dev")),
-                   out_specs=out_specs,
-                   check_rep=False)
+    fn = jax.shard_map(round_fn, mesh=mesh,
+                       in_specs=(gspec, P("dev"), P("dev"), P("dev"),
+                                 P("dev"), P("dev"), P("dev")),
+                       out_specs=out_specs,
+                       check_vma=False)
     return jax.jit(fn)
 
 
@@ -859,10 +861,10 @@ def pagerank_distributed(stacked_rg: Graph, mesh, out_degrees,
 
         gspec = Graph(row_ptr=P("dev"), col_idx=P("dev"),
                       edge_w=P("dev"))
-        fn = jax.jit(shard_map(trav_fn, mesh=mesh,
-                               in_specs=(gspec, P(), P(), P()),
-                               out_specs=(P(), P()),
-                               check_rep=False))
+        fn = jax.jit(jax.shard_map(trav_fn, mesh=mesh,
+                                   in_specs=(gspec, P(), P(), P()),
+                                   out_specs=(P(), P()),
+                                   check_vma=False))
         t0 = time.perf_counter()
         rank, r = fn(stacked_rg, rank, inv_out, sink)
         jax.block_until_ready(rank)

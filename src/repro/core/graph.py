@@ -127,23 +127,32 @@ def from_edge_list(src: np.ndarray, dst: np.ndarray, num_vertices: int,
     if dedup and len(src):
         key = src * np.int64(num_vertices) + dst
         if weights is None:
-            _, keep = np.unique(key, return_index=True)
-            src, dst = src[keep], dst[keep]
+            key = np.unique(key)
         else:
-            weights = np.asarray(weights)
-            # sort by (key, weight): the first edge of each key run is
-            # its minimum-weight duplicate
-            by_w = np.lexsort((weights, key))
-            key, src, dst, weights = (key[by_w], src[by_w], dst[by_w],
-                                      weights[by_w])
-            keep = np.concatenate([[True], key[1:] != key[:-1]])
-            src, dst, weights = src[keep], dst[keep], weights[keep]
-    order = np.lexsort((dst, src))
-    src, dst = src[order], dst[order]
+            # each run of equal keys collapses to its minimum weight;
+            # the order inside a run does not matter, so one unstable
+            # sort of the keys replaces a (key, weight) lexsort
+            order = np.argsort(key)
+            key, weights = key[order], np.asarray(weights)[order]
+            first = np.flatnonzero(
+                np.concatenate([[True], key[1:] != key[:-1]]))
+            key = key[first]
+            weights = np.minimum.reduceat(weights, first)
+        # the unique keys ascend, so the edges come out in the (src,
+        # dst) order the CSR needs
+        src, dst = np.divmod(key, np.int64(num_vertices))
+    else:
+        # one stable sort on the (src, dst) key: parallel edges keep
+        # their input order, and already-sorted input sorts in O(E)
+        order = np.argsort(src * np.int64(num_vertices) + dst,
+                           kind="stable")
+        src, dst = src[order], dst[order]
+        if weights is not None:
+            weights = np.asarray(weights)[order]
     if weights is None:
         weights = np.ones(len(src), dtype=np.int32)
     else:
-        weights = np.asarray(weights, dtype=np.int32)[order]
+        weights = np.asarray(weights, dtype=np.int32)
     counts = np.bincount(src, minlength=num_vertices).astype(np.int32)
     row_ptr = np.zeros(num_vertices + 1, dtype=np.int32)
     np.cumsum(counts, out=row_ptr[1:])
@@ -171,11 +180,13 @@ def rmat(scale: int, edge_factor: int = 16, seed: int = 0,
     ab, abc = a + b, a + b + c
     for bit in range(scale):
         r = rng.random(m)
-        # pick quadrant: 0=a 1=b 2=c 3=d
-        quad = np.select(
-            [r < a, r < ab, r < abc], [0, 1, 2], default=3)
-        src = (src << 1) | (quad >= 2)
-        dst = (dst << 1) | (quad & 1)
+        # pick quadrant 0=a 1=b 2=c 3=d: the number of cumulative
+        # thresholds r reaches
+        quad = (r >= a).astype(np.int8) + (r >= ab) + (r >= abc)
+        src <<= 1
+        src |= quad >= 2
+        dst <<= 1
+        dst |= quad & 1
     w = rng.integers(1, max_weight + 1, size=m) if weighted else None
     return from_edge_list(src, dst, n, weights=w)
 

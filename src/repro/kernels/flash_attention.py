@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import pallas_call
+
 NEG_INF = -1e30
 
 
@@ -64,9 +66,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "block_q", "block_k", "interpret"))
+    static_argnames=("causal", "block_q", "block_k"))
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128):
     """q: [B, S, H, hd]; k/v: [B, S, Hkv, hd]. Returns [B, S, H, hd].
 
     GQA is handled by repeating KV heads logically via the index map
@@ -87,7 +89,7 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
     kern = functools.partial(_flash_kernel, block_q=block_q,
                              block_k=block_k, seq_len=s, causal=causal,
                              sm_scale=sm_scale)
-    out = pl.pallas_call(
+    out = pallas_call(
         kern,
         grid=grid,
         in_specs=[
@@ -98,6 +100,5 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
         ],
         out_specs=pl.BlockSpec((1, block_q, hd), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, s, hd), q.dtype),
-        interpret=interpret,
     )(qg, kg, vg)
     return jnp.moveaxis(out.reshape(b, h, s, hd), 1, 2)

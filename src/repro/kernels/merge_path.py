@@ -4,139 +4,52 @@ Merge-path load balancing (Merrill & Garland's SpMV scheme, the
 segmented-scan form of Gunrock-LB) removes the inspector entirely:
 the frontier's whole edge range ``[0, total)`` is cut into equal-work
 tiles of ``tile_edges`` edge ids each, and every tile locates its own
-slice of the frontier by *co-ranked* binary search over the exclusive
-degree prefix sum ``start_e`` — a diagonal search on the (vertices,
-edges) merge matrix.  No degree bins, no huge-bin detection, no
-per-round planning of any kind: the only data-dependent quantity is
-``total``, a device scalar, which is why the executor drops into the
-fused device-resident traversal loop (DESIGN.md section 11) with zero
-host involvement.
+slice of the frontier by *co-ranked* search over the exclusive degree
+prefix sum ``start_e`` — a diagonal search on the (vertices, edges)
+merge matrix.  No degree bins, no huge-bin detection, no per-round
+planning of any kind: the only data-dependent quantity is ``total``, a
+device scalar, which is why the executor drops into the fused
+device-resident traversal loop (DESIGN.md section 11) with zero host
+involvement.
 
-Per grid step (one equal-work tile):
-
-1. two *scalar* co-rank searches bound the tile's source-slot window:
-   ``lo_j = rank(first edge id)`` and ``hi_j = rank(last edge id)`` —
-   the tile's diagonal intersections with the merge path;
-2. each lane then binary-searches its own edge id **restricted to**
-   ``[lo_j, hi_j + 1)`` — the window is typically a handful of slots
-   (a tile of E/T edges crosses few vertices unless degrees are tiny),
-   so the per-lane search touches a narrow, VPU-uniform span of
-   ``start_e`` instead of the whole array (contrast ``edge_lb.py``,
-   whose every lane searches the full ``[0, H)`` range);
-3. the tile emits (graph_edge, slot) pairs; the irregular gathers and
-   the scatter-combine stay in the XLA epilogue
-   (``ops.merge_path_apply*``), exactly like the other two backends.
-
-The per-lane loop keeps a fixed ``ceil(log2(H))`` trip count (runs of
-zero-degree slots can widen a window arbitrarily, so the bound cannot
-be lowered statically), but every iteration past the window's true
-depth is a no-op on converged lanes — the narrowing is where the
-merge-path locality comes from, the equal-work tiling is where the
-balance comes from.
+The tile mapping is the co-ranked windowed search of ``edge_lb.py``
+(:func:`repro.kernels.edge_lb.corank_map`): per tile, the two scalar
+co-ranks of its first and last edge id bound a window of at most
+``tile_edges`` slots (zero-degree frontier members are dropped first),
+and the kernel resolves every lane against that window only.  Here it
+runs over the frontier's WHOLE edge range rather than the huge bin.
+The tile emits (graph_edge, slot) pairs; the irregular gathers and the
+scatter-combine stay in the XLA epilogue (``ops.merge_path_apply*``),
+exactly like the other two backends.
 
 Enumeration contract: ids are dealt contiguously (tile t owns
 ``[t * tile_edges, (t+1) * tile_edges)``), so per-tile edge loads
 differ by at most one partial tail tile — the ``distribution`` knob of
 the other backends does not apply.  Ids at or past ``total`` are
-masked before any memory traffic.  Validated in interpret mode against
-a numpy searchsorted oracle (tests/test_fused.py).
+masked before any memory traffic.  Validated against a numpy
+searchsorted oracle (tests/test_fused.py).
 """
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
+
+from .edge_lb import corank_map
 
 
-def _kernel(start_ref, row_ref, total_ref, ge_ref, slot_ref, msk_ref,
-            *, tile_r: int, h: int):
-    i = pl.program_id(0)
-    tile = tile_r * 128
-    lin = (jax.lax.broadcasted_iota(jnp.int32, (tile_r, 128), 0) * 128
-           + jax.lax.broadcasted_iota(jnp.int32, (tile_r, 128), 1))
-    eid = i * tile + lin
-    total = total_ref[0, 0]
-    emask = eid < total
-    eid_c = jnp.where(emask, eid, 0)
-
-    start_e = start_ref[0, :]                      # [H] whole, in VMEM
-    row_start = row_ref[0, :]
-    steps = max(1, (h - 1).bit_length())
-
-    # ---- co-rank: scalar diagonal searches bound the slot window ----
-    def co_rank(x):
-        def body(_, carry):
-            lo, hi = carry
-            mid = (lo + hi) // 2
-            go_right = jnp.take(start_e, mid) <= x
-            return (jnp.where(go_right, mid + 1, lo),
-                    jnp.where(go_right, hi, mid))
-        lo, _ = jax.lax.fori_loop(
-            0, steps, body, (jnp.int32(0), jnp.int32(h)))
-        return jnp.clip(lo - 1, 0, h - 1)
-
-    t_lo = i * tile
-    t_hi = jnp.clip(total - 1, t_lo, t_lo + tile - 1)
-    lo_j = co_rank(jnp.int32(t_lo))                # first slot touched
-    hi_j = co_rank(t_hi)                           # last slot touched
-
-    # ---- per-lane search, restricted to [lo_j, hi_j + 1) ------------
-    lo = jnp.full_like(eid_c, lo_j)
-    hi = jnp.full_like(eid_c, hi_j + 1)
-
-    def body(_, carry):
-        lo, hi = carry
-        mid = (lo + hi) // 2
-        go_right = jnp.take(start_e, mid) <= eid_c
-        return (jnp.where(go_right, mid + 1, lo),
-                jnp.where(go_right, hi, mid))
-    lo, hi = jax.lax.fori_loop(0, steps, body, (lo, hi))
-    j = jnp.clip(lo - 1, 0, h - 1)
-
-    ge_ref[...] = jnp.where(emask,
-                            jnp.take(row_start, j)
-                            + (eid_c - jnp.take(start_e, j)), 0)
-    slot_ref[...] = j
-    msk_ref[...] = emask.astype(jnp.int32)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("ecap", "tile_edges", "interpret"))
+@functools.partial(jax.jit, static_argnames=("ecap", "tile_edges"))
 def merge_path_map(start_e: jax.Array, row_start: jax.Array,
                    total_edges: jax.Array, ecap: int, *,
-                   tile_edges: int = 2048, interpret: bool = True):
+                   tile_edges: int = 2048):
     """Run the merge-path mapping kernel over ``ecap`` edge ids.
 
     ``start_e`` / ``row_start`` are the ``[H]`` exclusive degree prefix
     sum and CSR row starts of the frontier members; ``total_edges`` is
     the live edge count (device scalar, ids past it are masked).
     Returns ``(graph_e, slot_j, mask)`` flat arrays of length
-    ``ceil(ecap / tile_edges) * tile_edges`` — each kernel grid step is
-    one equal-work tile of ``tile_edges`` consecutive edge ids.
+    ``ceil(ecap / tile_edges) * tile_edges`` (at least one tile) — each
+    kernel grid step is one equal-work tile of ``tile_edges``
+    consecutive edge ids.
     """
-    h = start_e.shape[0]
-    tile_r = tile_edges // 128
-    assert tile_edges % 128 == 0
-    grid = max(1, -(-ecap // tile_edges))
-    n_rows = grid * tile_r
-
-    out_shape = [
-        jax.ShapeDtypeStruct((n_rows, 128), jnp.int32),   # graph_e
-        jax.ShapeDtypeStruct((n_rows, 128), jnp.int32),   # slot j
-        jax.ShapeDtypeStruct((n_rows, 128), jnp.int32),   # mask
-    ]
-    kern = functools.partial(_kernel, tile_r=tile_r, h=h)
-    full = pl.BlockSpec((1, h), lambda i: (0, 0))
-    outs = pl.pallas_call(
-        kern,
-        grid=(grid,),
-        in_specs=[full, full, pl.BlockSpec((1, 1), lambda i: (0, 0))],
-        out_specs=[pl.BlockSpec((tile_r, 128), lambda i: (i, 0))] * 3,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(start_e[None, :], row_start[None, :],
-      jnp.asarray(total_edges, jnp.int32).reshape(1, 1))
-    ge, j, msk = (o.reshape(-1) for o in outs)
-    return ge, j, msk.astype(bool)
+    return corank_map(start_e, row_start, total_edges, ecap, tile_edges)

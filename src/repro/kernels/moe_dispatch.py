@@ -11,6 +11,10 @@ accumulator — a tile-parallel scan with an O(E) carry, instead of the
 O(T*K x E) one-hot cumsum matrix the pure-jnp oracle materializes
 (moe._positions_in_expert).  Output: pos[i] = #earlier slots routed to
 the same expert.
+
+Known gap: Mosaic has no lowering for the in-kernel ``cumsum``, so the
+TPU compile of this kernel fails.  ``repro.kernels.pallas_call`` never
+falls back to the interpreter on a TPU, so that error surfaces as is.
 """
 from __future__ import annotations
 
@@ -19,6 +23,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from . import pallas_call
 
 
 def _kernel(eid_ref, pos_ref, counts_ref, *, num_experts, tile):
@@ -41,9 +47,9 @@ def _kernel(eid_ref, pos_ref, counts_ref, *, num_experts, tile):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("num_experts", "tile", "interpret"))
+                   static_argnames=("num_experts", "tile"))
 def positions_in_expert_kernel(flat_expert, num_experts: int,
-                               tile: int = 1024, interpret: bool = True):
+                               tile: int = 1024):
     """flat_expert: [N] int32 -> pos: [N] int32 (arrival rank within
     expert)."""
     n = flat_expert.shape[0]
@@ -54,7 +60,7 @@ def positions_in_expert_kernel(flat_expert, num_experts: int,
         e = jnp.pad(e, (0, pad), constant_values=num_experts + 1)
     grid = np_ // tile
     kern = functools.partial(_kernel, num_experts=num_experts, tile=tile)
-    pos, _ = pl.pallas_call(
+    pos, _ = pallas_call(
         kern,
         grid=(grid,),
         in_specs=[pl.BlockSpec((1, tile), lambda i: (0, i))],
@@ -62,6 +68,5 @@ def positions_in_expert_kernel(flat_expert, num_experts: int,
                    pl.BlockSpec((1, num_experts), lambda i: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct((1, np_), jnp.int32),
                    jax.ShapeDtypeStruct((1, num_experts), jnp.int32)],
-        interpret=interpret,
     )(e[None, :])
     return pos[0, :n]

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 # backends neutralize inactive (vertex, query) slots with the exact
 # same combiner-identity rule (balancer.combine_neutral)
 from repro.core.balancer import _apply
+from repro.core.frontier import prefix_sum, spread_index
 
 from . import edge_lb as _edge_lb
 from . import merge_path as _merge_path
@@ -54,18 +55,19 @@ def edge_lb_apply_static(g, values, labels, fmask, hvidx, hdeg, hrow,
                          num_tiles: int, tile_edges: int):
     """Fully-jit LB entry: trace-safe body (no own jit wrapper)."""
     v = labels.shape[-1]
-    start_e = jnp.cumsum(hdeg) - hdeg
+    start_e = prefix_sum(hdeg) - hdeg
     vsafe = jnp.where(hvidx < v, hvidx, 0)
     hval = values[0, vsafe]            # kernel value plumbing: batch 0
     ge, j, _, mask = _edge_lb.edge_lb_map(
         start_e, hrow, hval, total, ecap,
         tile_edges=tile_edges, distribution=distribution,
         num_tiles=num_tiles)
+    ge = spread_index(mask, ge, g.col_idx.shape[0])
     dst = g.col_idx[ge]
     w = g.edge_w[ge]
     j = jnp.clip(j, 0, hvidx.shape[0] - 1)
     src = jnp.where(hvidx.shape[0] > 0, hvidx[j], 0)
-    ssafe = jnp.where(src < v, src, 0)
+    ssafe = spread_index(mask & (src < v), src, v)
     if op.direction == "push":
         live = fmask[:, ssafe]                           # [B, n]
         cand = op.msg(values[:, ssafe], w[None])
@@ -105,14 +107,15 @@ def merge_path_apply_static(g, values, labels, fmask, hvidx, hdeg, hrow,
     and ``num_tiles`` do not apply and are ignored."""
     del distribution, num_tiles
     v = labels.shape[-1]
-    start_e = jnp.cumsum(hdeg) - hdeg
+    start_e = prefix_sum(hdeg) - hdeg
     ge, j, mask = _merge_path.merge_path_map(
         start_e, hrow, total, ecap, tile_edges=tile_edges)
+    ge = spread_index(mask, ge, g.col_idx.shape[0])
     dst = g.col_idx[ge]
     w = g.edge_w[ge]
     j = jnp.clip(j, 0, hvidx.shape[0] - 1)
     src = jnp.where(hvidx.shape[0] > 0, hvidx[j], 0)
-    ssafe = jnp.where(src < v, src, 0)
+    ssafe = spread_index(mask & (src < v), src, v)
     if op.direction == "push":
         live = fmask[:, ssafe]                           # [B, n]
         cand = op.msg(values[:, ssafe], w[None])
@@ -157,6 +160,7 @@ def twc_bin_apply_static(g, values, labels, fmask, bvidx, bdeg, brow,
     ge, anchor, _, mask = _twc.twc_bin_map(
         bvidx, bdeg, brow, bval, width=width, chunk=chunk,
         sentinel=v)
+    ge = spread_index(mask, ge, g.col_idx.shape[0])
     dst = g.col_idx[ge]
     w = g.edge_w[ge]
     # the kernel may pad the bin to its vertex-tile size: recover the

@@ -26,11 +26,11 @@ def edge_lb_map_ref(start_e, row_start, hval, total_edges, n_enum,
 
 
 def twc_bin_map_ref(vidx, deg, row_start, val, *, width: int,
-                    chunk: int = 0, tile_v: int = 8,
-                    sentinel: int = 1 << 30):
+                    chunk: int = 0, sentinel: int = 1 << 30):
     """Oracle for twc_gather.twc_bin_map."""
+    from .twc_gather import ROW_PAD
     b = vidx.shape[0]
-    bp = -(-b // tile_v) * tile_v
+    bp = -(-b // ROW_PAD) * ROW_PAD
     pad = bp - b
     if pad:
         vidx = jnp.pad(vidx, (0, pad), constant_values=sentinel)

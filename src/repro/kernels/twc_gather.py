@@ -1,15 +1,21 @@
 """Pallas TPU kernel for the vertex-binned (TWC-analog) path.
 
-Each grid step processes a tile of ``tile_v`` frontier vertices from one
-degree bin; the bin's uniform width ``W`` is the lane dimension, so the
-inner trip count is identical across lanes (the TPU analogue of the
-warp-uniform execution TWC buys on GPUs).  Emits (graph_e, anchor, val,
-mask) tiles; gather/scatter is applied outside by XLA (see edge_lb.py
-for the rationale).
+Each grid step processes a tile of frontier vertices from one degree
+bin; the bin's uniform width ``W`` fixes the inner trip count, so it is
+identical across the tile (the TPU analogue of the warp-uniform
+execution TWC buys on GPUs).  Emits (graph_e, anchor, val, mask) tiles;
+gather/scatter is applied outside by XLA (see edge_lb.py for the
+rationale).
+
+TPU layout: a bin's per-vertex scalars arrive as ``[1, n]`` rows, so a
+tile of vertices is a lane-aligned ``(1, 128k)`` block, and the kernel
+writes ``[W, tile]`` blocks (edge offset on sublanes, vertex on lanes)
+where every vertex's scalars broadcast down its own lane.  The wrapper
+transposes the result back to the ``[n, W]`` contract.
 
 ``chunk`` — the pass index for unbounded bins (each pass covers edges
 [chunk*W, (chunk+1)*W) of every vertex) — is a *runtime scalar operand*
-fed through a (1, 1) block, not a compile-time constant: the fully-jit
+(a scalar-prefetch argument), not a compile-time constant: the fully-jit
 SPMD round (balancer.relax_spmd) iterates chunks with a
 ``lax.while_loop`` whose trip count is data-dependent, so the kernel
 must accept a traced chunk.  The host-driven round passes Python ints,
@@ -29,66 +35,66 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import pallas_call
+
+_LANES = 128
+_BLOCK_ELEMS = 1 << 17        # int32 elements per output block (512 KiB)
+ROW_PAD = 8                   # returned rows: the bin size rounded up to this
 
 
 def _kernel(chunk_ref, vidx_ref, deg_ref, row_ref, val_ref,
             ge_ref, anchor_ref, val_out_ref, msk_ref,
             *, width: int, sentinel: int):
-    deg = deg_ref[0, :]                        # [tile_v]
-    row = row_ref[0, :]
-    vid = vidx_ref[0, :]
-    val = val_ref[0, :]
-    chunk = chunk_ref[0, 0]
-    off = (chunk * width
-           + jax.lax.broadcasted_iota(jnp.int32, (deg.shape[0], width), 1))
-    emask = (off < deg[:, None]) & (vid[:, None] < sentinel)
-    ge_ref[...] = jnp.where(emask, row[:, None] + off, 0)
-    anchor_ref[...] = jnp.broadcast_to(vid[:, None], emask.shape)
-    val_out_ref[...] = jnp.broadcast_to(val[:, None], emask.shape)
+    # transposed tile: edge offsets on sublanes, bin members on lanes,
+    # so each member's scalars broadcast down its own lane column
+    vid = vidx_ref[...]                         # [1, tile]
+    off = (chunk_ref[0] * width
+           + jax.lax.broadcasted_iota(jnp.int32, ge_ref.shape, 0))
+    emask = (off < deg_ref[...]) & (vid < sentinel)
+    ge_ref[...] = jnp.where(emask, row_ref[...] + off, 0)
+    anchor_ref[...] = jnp.broadcast_to(vid, emask.shape)
+    val_out_ref[...] = jnp.broadcast_to(val_ref[...], emask.shape)
     msk_ref[...] = emask.astype(jnp.int32)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("width", "tile_v", "sentinel", "interpret"))
+@functools.partial(jax.jit, static_argnames=("width", "sentinel"))
 def twc_bin_map(vidx: jax.Array, deg: jax.Array, row_start: jax.Array,
                 val: jax.Array, *, width: int,
-                chunk: jax.Array | int = 0,
-                tile_v: int = 8, sentinel: int = 1 << 30,
-                interpret: bool = True):
-    """Expand one degree bin into (graph_e, anchor, val, mask) tiles."""
+                chunk: jax.Array | int = 0, sentinel: int = 1 << 30):
+    """Expand one degree bin into (graph_e, anchor, val, mask) tiles.
+
+    Returns four ``[bp, width]`` arrays, ``bp`` = the bin size rounded
+    up to ``ROW_PAD``; padded rows carry ``anchor = sentinel`` and an
+    all-False mask.  The kernel works on the transposed ``[width,
+    tile]`` layout (``tile`` = a multiple of 128 members, sized so an
+    output block stays near 512 KiB) and the result is transposed back
+    here."""
     b = vidx.shape[0]
-    bp = -(-b // tile_v) * tile_v
-    pad = bp - b
+    bp = -(-b // ROW_PAD) * ROW_PAD
+    tile = max(_LANES, min(_BLOCK_ELEMS // width, 16 * _LANES)
+               // _LANES * _LANES)
+    bk = -(-bp // tile) * tile
+    pad = bk - b
     if pad:
         vidx = jnp.pad(vidx, (0, pad), constant_values=sentinel)
         deg = jnp.pad(deg, (0, pad))
         row_start = jnp.pad(row_start, (0, pad))
         val = jnp.pad(val, (0, pad))
-    grid = bp // tile_v
-    # lane dim must be 128-aligned for the MXU/VPU; widths are powers of
-    # two >= 8 in our configs, pad up when narrow.
-    wp = max(width, 128) if width % 128 else width
-    kern = functools.partial(_kernel, width=wp, sentinel=sentinel)
-    chunk = jnp.asarray(chunk, jnp.int32).reshape(1, 1)
-    scalar = pl.BlockSpec((1, 1), lambda i: (0, 0))
-    vec = pl.BlockSpec((1, tile_v), lambda i: (0, i))
-    out_shape = [
-        jax.ShapeDtypeStruct((bp, wp), jnp.int32),
-        jax.ShapeDtypeStruct((bp, wp), jnp.int32),
-        jax.ShapeDtypeStruct((bp, wp), val.dtype),
-        jax.ShapeDtypeStruct((bp, wp), jnp.int32),
-    ]
-    outs = pl.pallas_call(
+    kern = functools.partial(_kernel, width=width, sentinel=sentinel)
+    vec = pl.BlockSpec((1, tile), lambda i, chunk: (0, i))
+    out = pl.BlockSpec((width, tile), lambda i, chunk: (0, i))
+    outs = pallas_call(
         kern,
-        grid=(grid,),
-        in_specs=[scalar, vec, vec, vec, vec],
-        out_specs=[pl.BlockSpec((tile_v, wp), lambda i: (i, 0))] * 4,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(chunk, vidx[None, :], deg[None, :], row_start[None, :], val[None, :])
-    ge, anchor, v, msk = outs
-    if wp != width:
-        # only the first `width` lanes are real when width < 128
-        ge, anchor, v, msk = (x[:, :width] for x in (ge, anchor, v, msk))
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(bk // tile,),
+            in_specs=[vec] * 4, out_specs=[out] * 4),
+        out_shape=[jax.ShapeDtypeStruct((width, bk), jnp.int32),
+                   jax.ShapeDtypeStruct((width, bk), jnp.int32),
+                   jax.ShapeDtypeStruct((width, bk), val.dtype),
+                   jax.ShapeDtypeStruct((width, bk), jnp.int32)],
+    )(jnp.asarray(chunk, jnp.int32).reshape(1), vidx[None, :],
+      deg[None, :], row_start[None, :], val[None, :])
+    ge, anchor, v, msk = (o.T[:bp] for o in outs)
     return ge, anchor, v, msk.astype(bool)

@@ -4,12 +4,21 @@ state)."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the train step places data
+    with ``with_sharding_constraint``, which refuses the Explicit axes
+    ``make_mesh`` defaults to."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def data_axes(multi_pod: bool):
@@ -21,4 +30,4 @@ def data_axes(multi_pod: bool):
 def make_host_mesh(num_devices: int | None = None):
     """Small CPU mesh for tests/examples: (1, N) data×model."""
     n = num_devices or len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return _auto_mesh((n, 1), ("data", "model"))

@@ -8,7 +8,6 @@ import pytest
 SCRIPT = r"""
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.core.gluon import device_mesh
 from repro.optim.grad_compress import compressed_psum
@@ -20,8 +19,8 @@ local = jnp.asarray(rng.normal(size=(4, 64, 32)).astype(np.float32))
 def f(g):
     return compressed_psum({"w": g[0]}, "dev")["w"]
 
-out = jax.jit(shard_map(f, mesh=mesh, in_specs=(P("dev"),),
-                        out_specs=P(), check_rep=False))(local)
+out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P("dev"),),
+                            out_specs=P(), check_vma=False))(local)
 want = np.asarray(local).sum(axis=0)
 got = np.asarray(out)
 # error bounded by #participants * quantum

@@ -212,3 +212,74 @@ def test_version_starts_at_zero_and_bumps():
     leaves, treedef = jax.tree_util.tree_flatten(g)
     g2 = jax.tree_util.tree_unflatten(treedef, leaves)
     assert g2.version == 0
+
+
+def _csr_reference(src, dst, n, weights=None, dedup=True):
+    """The CSR build as it was before the set-up speed-up: a (key,
+    weight) lexsort for the weighted dedup, then a (src, dst) lexsort
+    of the survivors.  Kept as the oracle the faster build must match
+    bitwise."""
+    src = np.asarray(src, dtype=np.int64)
+    dst = np.asarray(dst, dtype=np.int64)
+    if dedup and len(src):
+        key = src * np.int64(n) + dst
+        if weights is None:
+            _, keep = np.unique(key, return_index=True)
+            src, dst = src[keep], dst[keep]
+        else:
+            weights = np.asarray(weights)
+            by_w = np.lexsort((weights, key))
+            key, src, dst, weights = (key[by_w], src[by_w], dst[by_w],
+                                      weights[by_w])
+            keep = np.concatenate([[True], key[1:] != key[:-1]])
+            src, dst, weights = src[keep], dst[keep], weights[keep]
+    order = np.lexsort((dst, src))
+    src, dst = src[order], dst[order]
+    if weights is None:
+        weights = np.ones(len(src), dtype=np.int32)
+    else:
+        weights = np.asarray(weights, dtype=np.int32)[order]
+    row_ptr = np.zeros(n + 1, dtype=np.int32)
+    np.cumsum(np.bincount(src, minlength=n), out=row_ptr[1:])
+    return row_ptr, dst.astype(np.int32), weights
+
+
+def _rmat_edges_reference(scale, edge_factor, seed, max_weight=100):
+    """The RMAT quadrant draw as it was written with ``np.select``."""
+    rng = np.random.default_rng(seed)
+    m = edge_factor << scale
+    src = np.zeros(m, dtype=np.int64)
+    dst = np.zeros(m, dtype=np.int64)
+    for _ in range(scale):
+        r = rng.random(m)
+        quad = np.select([r < 0.57, r < 0.76, r < 0.95], [0, 1, 2],
+                         default=3)
+        src = (src << 1) | (quad >= 2)
+        dst = (dst << 1) | (quad & 1)
+    return src, dst, rng.integers(1, max_weight + 1, size=m)
+
+
+def _assert_csr(g, want):
+    for got, ref in zip((g.row_ptr, g.col_idx, g.edge_w), want):
+        np.testing.assert_array_equal(np.asarray(got), ref)
+        assert np.asarray(got).dtype == ref.dtype
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_rmat_matches_reference_build(seed):
+    src, dst, w = _rmat_edges_reference(9, 16, seed)
+    _assert_csr(G.rmat(9, 16, seed=seed), _csr_reference(src, dst, 512, w))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("weighted", [True, False])
+@pytest.mark.parametrize("dedup", [True, False])
+def test_from_edge_list_matches_reference_build(seed, weighted, dedup):
+    """Random multigraphs with many parallel edges and self-loops."""
+    rng = np.random.default_rng(seed)
+    n = 97
+    src = rng.integers(0, n, 3000)
+    dst = rng.integers(0, n, 3000)
+    w = rng.integers(-5, 50, 3000) if weighted else None
+    _assert_csr(G.from_edge_list(src, dst, n, weights=w, dedup=dedup),
+                _csr_reference(src, dst, n, w, dedup))

@@ -31,7 +31,8 @@ for arch in ["llama3-8b", "deepseek-moe-16b", "mamba2-2.7b"]:
     ref_step = jax.jit(make_train_step(cfg, OptConfig()))
     rp, ro, rm = ref_step(params, opt, batch)
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     pspec = SH.param_specs(jax.eval_shape(lambda: params))
     named = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t,
                                    is_leaf=lambda x: isinstance(x, P))
