@@ -9,10 +9,6 @@ Selectors and what each script reproduces:
 * ``table2``   (table2_strategies.py)   — Table 2: wall clock per
   (input x app x strategy); also times the fully-jit SPMD round
   (``alb_spmd`` rows) and derives ALB-vs-TWC speedups.
-* ``table2sim`` (table2_simulated.py)   — Table 2 with the paper's GPU
-  cost model instead of wall clock (deterministic CI-friendly numbers).
-* ``fig5``     (fig5_load_distribution.py) — Fig 1/5: per-tile edge
-  loads round by round, TWC vs ALB, host and SPMD rounds.
 * ``fig6``     (fig6_scaling.py)        — Fig 6/10: 1..8-device BSP
   scaling of the Gluon-analog runtime, TWC vs ALB, replicated vs
   mirror sync; also writes benchmarks/out/fig6_scaling.json with
@@ -64,9 +60,8 @@ from __future__ import annotations
 import sys
 
 
-SELECTORS = ("table2", "table2sim", "fig5", "fig6", "fig8", "fig9",
-             "qps", "serve", "direction", "update", "fused", "fleet",
-             "roofline")
+SELECTORS = ("table2", "fig6", "fig8", "fig9", "qps", "serve",
+             "direction", "update", "fused", "fleet", "roofline")
 
 
 def main() -> None:
@@ -87,12 +82,6 @@ def main() -> None:
     if "table2" in which:
         from . import table2_strategies
         table2_strategies.run()
-    if "table2sim" in which:
-        from . import table2_simulated
-        table2_simulated.run()
-    if "fig5" in which:
-        from . import fig5_load_distribution
-        fig5_load_distribution.run()
     if "fig6" in which:
         from . import fig6_scaling
         fig6_scaling.run()

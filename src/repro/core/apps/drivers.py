@@ -201,16 +201,23 @@ def _loop(g: Graph, values_of, labels, frontier, cfg, op,
     t0 = time.perf_counter()
     rounds = 0
     while rounds < max_rounds:
-        old = labels
-        new, st, active = _round(g, values_of(labels), labels, frontier,
-                                 cfg, op, collect_stats, mode,
-                                 return_active=True)
-        if not bool(np.any(active)):
-            break                      # frontier empty: converged
-        labels = new
-        if post_round is not None:
-            labels = post_round(labels)
-        frontier = next_frontier(old, labels, frontier)
+        # the round's host spans (``graph.round`` around ``graph.counts``
+        # / ``graph.plan`` / ``graph.passes`` inside ``relax``, and
+        # ``graph.update``) put each device idle gap of a profiler trace
+        # down to a phase of the round; the last span is the probe that
+        # finds the frontier empty
+        with jax.profiler.TraceAnnotation("graph.round", round=rounds):
+            old = labels
+            new, st, active = _round(g, values_of(labels), labels,
+                                     frontier, cfg, op, collect_stats, mode,
+                                     return_active=True)
+            if not bool(np.any(active)):
+                break                  # frontier empty: converged
+            with jax.profiler.TraceAnnotation("graph.update"):
+                labels = new
+                if post_round is not None:
+                    labels = post_round(labels)
+                frontier = next_frontier(old, labels, frontier)
         if collect_stats and st is not None:
             stats.append(st)
         rounds += 1
@@ -337,7 +344,7 @@ def _kcore_fused(g: Graph, deg, frontier, dead_acc, k: int,
     :func:`repro.core.balancer.relax_fused_round`, and the
     newly-dead bookkeeping — the host loop's ``post_round`` logic —
     moves into the loop body unchanged."""
-    st0 = (_fused_stats_init(max_rounds, 1, cfg.num_tiles)
+    st0 = (_fused_stats_init(max_rounds, 1)
            if collect_stats else None)
 
     def cond(carry):
@@ -444,7 +451,7 @@ def _pagerank_fused(rg: Graph, inv_out, sink, damping: float,
     n = inv_out.shape[0]
     rank0 = jnp.full((n,), 1.0 / n, dtype=jnp.float32)
     frontier = full_frontier(n)
-    st0 = (_fused_stats_init(max_rounds, 1, cfg.num_tiles)
+    st0 = (_fused_stats_init(max_rounds, 1)
            if collect_stats else None)
 
     def cond(carry):

@@ -104,7 +104,7 @@ class BalancerConfig:
     medium_width: int = 128          # warp-level bin
     large_width: int = 1024          # CTA chunk width (per pass)
     distribution: str = "cyclic"     # cyclic | blocked (Section 4.1)
-    num_tiles: int = 64              # "thread blocks" for stats/kernels
+    num_tiles: int = 64              # "thread blocks": LB deal/kernels
     use_pallas: bool = False         # route hot loops through Pallas
     lb_tile_edges: int = 2048        # edge tile per grid step (LB kernel)
     direction: str = "push"          # push | pull | adaptive (sec. 9)
@@ -287,13 +287,33 @@ def resolve_direction_device(cfg: BalancerConfig, frontier_size,
 
 
 # ---------------------------------------------------------------------------
-# host-sync accounting: the per-round blocking device->host transfers
-# each execution mode performs, as an assertable number (the structural
-# realization of the "zero per-round host syncs" property of the fused
-# mode — no wall-clock measurement involved)
+# process-wide counters: named, monotonic host integers, bumped from
+# values a round already holds on the host (never a device read), so
+# that they are always on.  ``host_transfers`` counts the per-round
+# blocking device->host transfers each execution mode performs, as an
+# assertable number (the structural realization of the "zero per-round
+# host syncs" property of the fused mode — no wall-clock measurement
+# involved); the slot counters count the work the host round's edge
+# passes issue against the frontier edges they carry
 # ---------------------------------------------------------------------------
 
-_HOST_TRANSFERS = [0]
+_COUNTERS = {
+    "host_transfers": 0,
+    # bin passes: slots issued (bin capacity x width, per chunk pass)
+    # and the bins' frontier edges
+    "bin_slots": 0,
+    "bin_edges": 0,
+    # LB pass: edge ids enumerated (:func:`_lb_enum_size`) and the huge
+    # bin's frontier edges
+    "lb_slots": 0,
+    "lb_edges": 0,
+}
+
+
+def counter_snapshot() -> dict:
+    """Every process-wide counter's value now; a traversal's counts are
+    the differences of two snapshots around it."""
+    return dict(_COUNTERS)
 
 
 def _note_host_transfer(n: int = 1) -> None:
@@ -306,7 +326,7 @@ def _note_host_transfer(n: int = 1) -> None:
     setup (e.g. the cached pull enumeration) and the final label fetch
     are deliberately NOT counted — ``host_transfers`` measures the
     per-round round-trip cost the fused mode eliminates."""
-    _HOST_TRANSFERS[0] += n
+    _COUNTERS["host_transfers"] += n
 
 
 def host_transfer_count() -> int:
@@ -314,7 +334,7 @@ def host_transfer_count() -> int:
     points (see :func:`_note_host_transfer`).  Callers measure a
     traversal's syncs as the delta across it; ``mode="fused"`` must
     leave the counter unchanged between dispatch and final fetch."""
-    return _HOST_TRANSFERS[0]
+    return _COUNTERS["host_transfers"]
 
 
 # ---------------------------------------------------------------------------
@@ -393,8 +413,6 @@ class RoundStats(NamedTuple):
     edges_twc: int          # edges processed by the vertex-binned path
     edges_lb: int           # edges processed by the edge-balanced path
     lb_invoked: bool        # did the inspector fire the LB executor?
-    tile_loads_twc: np.ndarray   # per-tile edge counts, TWC path
-    tile_loads_lb: np.ndarray    # per-tile edge counts, LB path
     mirrors_synced: int = 0  # label entries exchanged by the BSP sync
     bytes_synced: int = 0    # ... as LOGICAL bytes: index word + [B]
     #                          payload per exchanged vertex (0 outside
@@ -420,10 +438,6 @@ class RoundStats(NamedTuple):
                    edges_twc=int(s.edges_twc),
                    edges_lb=int(s.edges_lb),
                    lb_invoked=bool(s.lb_invoked),
-                   tile_loads_twc=np.asarray(s.tile_loads_twc,
-                                             dtype=np.int64),
-                   tile_loads_lb=np.asarray(s.tile_loads_lb,
-                                            dtype=np.int64),
                    mirrors_synced=int(s.mirrors_synced),
                    bytes_synced=int(s.bytes_synced),
                    bytes_wire=int(s.bytes_wire),
@@ -444,8 +458,6 @@ class RoundStatsDev(NamedTuple):
     edges_twc: jax.Array         # int32 scalar
     edges_lb: jax.Array          # int32 scalar
     lb_invoked: jax.Array        # bool scalar
-    tile_loads_twc: jax.Array    # int32[num_tiles]
-    tile_loads_lb: jax.Array     # int32[num_tiles]
     mirrors_synced: jax.Array    # int32 scalar (filled in by gluon.py)
     bytes_synced: jax.Array      # int32 scalar (filled in by gluon.py)
     bytes_wire: jax.Array = np.int32(0)  # int32 scalar: post-encode
@@ -516,30 +528,38 @@ def _bin_pass_impl(g: Graph, values, labels, fmask, vidx, deg, row_start,
     Shapes: values/labels/fmask: [B, V];  vidx/deg/row_start: [N]
     (union-frontier bin members);  produces an [N, width] edge tile
     shared by the whole batch.
+
+    The work sits in three named scopes, which a profiler trace
+    carries as op metadata: ``edges`` (slot -> edge ids and the
+    ``col_idx``/``edge_w`` gathers), ``sources`` (the ``fmask``/
+    ``values`` gathers and ``op.msg``) and ``combine`` (the scatter).
     """
     v = labels.shape[-1]
-    base = jnp.asarray(chunk, jnp.int32) * width
-    off = base + jnp.arange(width, dtype=jnp.int32)[None, :]      # [1,W]
-    emask = off < deg[:, None]                                     # [N,W]
-    graph_e = spread_index(emask, row_start[:, None] + off,
-                           g.col_idx.shape[0])
-    dst = g.col_idx[graph_e]
-    w = g.edge_w[graph_e]
-    vsafe = jnp.where(vidx < v, vidx, 0)
+    with jax.named_scope("edges"):
+        base = jnp.asarray(chunk, jnp.int32) * width
+        off = base + jnp.arange(width, dtype=jnp.int32)[None, :]  # [1,W]
+        emask = off < deg[:, None]                                 # [N,W]
+        graph_e = spread_index(emask, row_start[:, None] + off,
+                               g.col_idx.shape[0])
+        dst = g.col_idx[graph_e]
+        w = g.edge_w[graph_e]
     if op.direction == "push":
-        live = fmask[:, vsafe][:, :, None]                         # [B,N,1]
-        val = values[:, vsafe][:, :, None]                         # [B,N,1]
-        cand = op.msg(val, w[None])
-        new = _apply(labels, dst, cand, emask, live, op.combine)
+        with jax.named_scope("sources"):
+            vsafe = jnp.where(vidx < v, vidx, 0)
+            live = fmask[:, vsafe][:, :, None]                     # [B,N,1]
+            val = values[:, vsafe][:, :, None]                     # [B,N,1]
+            cand = op.msg(val, w[None])
+        target = dst
     else:  # pull: value AND activity gathered at the in-neighbour
         # (``dst`` in the reverse CSR is the original edge's source),
         # candidate scattered at the anchor — DESIGN.md section 9
-        live = fmask[:, dst]                                       # [B,N,W]
-        val = values[:, dst]                                       # [B,N,W]
-        cand = op.msg(val, w[None])
-        anchor = jnp.broadcast_to(vidx[:, None], emask.shape)
-        new = _apply(labels, anchor, cand, emask, live, op.combine)
-    return new
+        with jax.named_scope("sources"):
+            live = fmask[:, dst]                                   # [B,N,W]
+            val = values[:, dst]                                   # [B,N,W]
+            cand = op.msg(val, w[None])
+        target = jnp.broadcast_to(vidx[:, None], emask.shape)
+    with jax.named_scope("combine"):
+        return _apply(labels, target, cand, emask, live, op.combine)
 
 
 _bin_pass = partial(jax.jit, static_argnames=("width", "op"))(_bin_pass_impl)
@@ -558,6 +578,13 @@ def _segment_values(starts, vals, n: int):
     acc = scatter_rows(jnp.zeros((1, n), vals.dtype), starts, step[None],
                        "add")[0]
     return prefix_sum(acc)
+
+
+def _lb_enum_size(ecap: int, num_tiles: int) -> int:
+    """Edge ids the LB pass enumerates for ``ecap``: the next multiple
+    of ``num_tiles``, so that the blocked deal is a bijection of them
+    and cannot miss edges."""
+    return -(-ecap // num_tiles) * num_tiles
 
 
 def _lb_pass_impl(g: Graph, values, labels, fmask, hidx, hdeg, hrow_start,
@@ -581,38 +608,45 @@ def _lb_pass_impl(g: Graph, values, labels, fmask, hidx, hdeg, hrow_start,
     The prefix sum and the deal are computed once per round over the
     union frontier's huge bin; ``fmask[:, src]`` recovers which queries
     the edge's source is actually active in (DESIGN.md section 7).
+
+    Named scopes as in :func:`_bin_pass_impl`, plus ``enumerate``: the
+    prefix sum, the id -> slot values and the deal.
     """
     v = labels.shape[-1]
-    start_e = prefix_sum(hdeg) - hdeg                  # exclusive prefix
-    # enumerate a multiple of num_tiles so the blocked permutation below
-    # is a bijection of [0, n_enum) and cannot miss edges
-    w_per = -(-ecap // num_tiles)
-    n_enum = w_per * num_tiles
-    eid = jnp.arange(n_enum, dtype=jnp.int32)
-    # per id in natural order: graph edge = id + (row start - prefix
-    # start) of its slot, and the slot's vertex
-    graph_e = _segment_values(start_e, hrow_start - start_e, n_enum) + eid
-    src = _segment_values(start_e, hidx, n_enum)
-    if distribution == "blocked":
-        # thread T_i gets the contiguous chunk [i*w_per, (i+1)*w_per):
-        # lane-major order becomes strided by w_per (Figure 4 right).
-        eid = (eid % num_tiles) * w_per + eid // num_tiles
-        graph_e, src = graph_e[eid], src[eid]
-    emask = eid < total_edges
-    graph_e = spread_index(emask, graph_e, g.col_idx.shape[0])
-    dst = g.col_idx[graph_e]
-    w = g.edge_w[graph_e]
-    ssafe = spread_index(emask & (src < v), src, v)
-    if op.direction == "push":
-        live = fmask[:, ssafe]                         # [B, n_enum]
-        cand = op.msg(values[:, ssafe], w[None])
-        return _apply(labels, dst, cand, emask, live, op.combine)
-    else:
-        # pull: liveness comes from the in-neighbour (``dst`` of the
-        # reverse CSR), the anchor ``src`` receives the candidate
-        live = fmask[:, dst]                           # [B, n_enum]
-        cand = op.msg(values[:, dst], w[None])
-        return _apply(labels, src, cand, emask, live, op.combine)
+    with jax.named_scope("enumerate"):
+        start_e = prefix_sum(hdeg) - hdeg              # exclusive prefix
+        n_enum = _lb_enum_size(ecap, num_tiles)
+        w_per = n_enum // num_tiles
+        eid = jnp.arange(n_enum, dtype=jnp.int32)
+        # per id in natural order: graph edge = id + (row start - prefix
+        # start) of its slot, and the slot's vertex
+        graph_e = (_segment_values(start_e, hrow_start - start_e, n_enum)
+                   + eid)
+        src = _segment_values(start_e, hidx, n_enum)
+        if distribution == "blocked":
+            # thread T_i gets the contiguous chunk [i*w_per, (i+1)*w_per):
+            # lane-major order becomes strided by w_per (Figure 4 right).
+            eid = (eid % num_tiles) * w_per + eid // num_tiles
+            graph_e, src = graph_e[eid], src[eid]
+    with jax.named_scope("edges"):
+        emask = eid < total_edges
+        graph_e = spread_index(emask, graph_e, g.col_idx.shape[0])
+        dst = g.col_idx[graph_e]
+        w = g.edge_w[graph_e]
+    with jax.named_scope("sources"):
+        if op.direction == "push":
+            ssafe = spread_index(emask & (src < v), src, v)
+            live = fmask[:, ssafe]                     # [B, n_enum]
+            cand = op.msg(values[:, ssafe], w[None])
+            target = dst
+        else:
+            # pull: liveness comes from the in-neighbour (``dst`` of the
+            # reverse CSR), the anchor ``src`` receives the candidate
+            live = fmask[:, dst]                       # [B, n_enum]
+            cand = op.msg(values[:, dst], w[None])
+            target = src
+    with jax.named_scope("combine"):
+        return _apply(labels, target, cand, emask, live, op.combine)
 
 
 _lb_pass = partial(jax.jit, static_argnames=(
@@ -622,24 +656,6 @@ _lb_pass = partial(jax.jit, static_argnames=(
 register_executor(ExecutorPair("xla",
                                bin_host=_bin_pass, bin_jit=_bin_pass_impl,
                                lb_host=_lb_pass, lb_jit=_lb_pass_impl))
-
-
-@partial(jax.jit, static_argnames=("num_tiles",))
-def _tile_loads(deg, valid, num_tiles: int):
-    """Per-tile edge counts when frontier vertices are dealt to tiles in
-    compacted order (Fig 1/5 instrumentation)."""
-    f = deg.shape[0]
-    tile = (jnp.arange(f, dtype=jnp.int32) * num_tiles) // max(f, 1)
-    return jnp.zeros((num_tiles,), jnp.int32).at[tile].add(
-        jnp.where(valid, deg, 0).astype(jnp.int32))
-
-
-def _lb_tile_loads(total, num_tiles: int):
-    """Edge-balanced deal: per-tile loads differ by at most one edge."""
-    total = jnp.asarray(total, jnp.int32)
-    return (total // num_tiles
-            + (jnp.arange(num_tiles, dtype=jnp.int32)
-               < total % num_tiles).astype(jnp.int32))
 
 
 # ---------------------------------------------------------------------------
@@ -859,8 +875,8 @@ def _run_plan_host(gr: Graph, values, labels, fmask, plan: RoundPlan,
     bin/LB member arrays — shared by the push path (members gathered
     from this round's frontier) and the pull path (members cached per
     graph by :func:`_pull_enum`).  ``stats`` is the mutable RoundStats
-    dict or None."""
-    v = labels.shape[-1]
+    dict or None.  Bumps the slot counters (:func:`counter_snapshot`)
+    from the bins' host counts and static capacities."""
     for spec, entry in zip(plan.bins, bins):
         if entry is None:
             continue
@@ -869,10 +885,10 @@ def _run_plan_host(gr: Graph, values, labels, fmask, plan: RoundPlan,
         for c in range(passes):
             labels = ex.bin_host(gr, values, labels, fmask, bvidx,
                                  bdeg, brow, spec.width, op, c)
+        _COUNTERS["bin_slots"] += passes * bvidx.shape[0] * spec.width
+        _COUNTERS["bin_edges"] += edge_sum
         if stats is not None:
             stats["edges_twc"] += edge_sum
-            stats["tile_loads_twc"] += np.asarray(
-                _tile_loads(bdeg, bvidx < v, cfg.num_tiles))
     if lb is not None:
         total, hvidx, hdeg, hrow = lb
         ecap = next_bucket(total, minimum=cfg.lb_tile_edges)
@@ -880,11 +896,11 @@ def _run_plan_host(gr: Graph, values, labels, fmask, plan: RoundPlan,
                             hrow, jnp.int32(total), ecap, op,
                             cfg.distribution, cfg.num_tiles,
                             cfg.lb_tile_edges)
+        _COUNTERS["lb_slots"] += _lb_enum_size(ecap, cfg.num_tiles)
+        _COUNTERS["lb_edges"] += total
         if stats is not None:
             stats["edges_lb"] = total
             stats["lb_invoked"] = True
-            stats["tile_loads_lb"] = np.asarray(
-                _lb_tile_loads(total, cfg.num_tiles), dtype=np.int64)
     return labels
 
 
@@ -922,48 +938,52 @@ def relax(g: Graph, values: jax.Array, labels: jax.Array,
     observing it costs no extra device round-trip.
     """
     batched = labels.ndim == 2
-    if not batched:
-        values, labels, frontier = (values[None], labels[None],
-                                    frontier[None])
-    b, v = labels.shape
-    plan = effective_plan(cfg)
-    # validate direction x operator up front (even when adaptive ends
-    # up resolving to push every round, a bad pairing is a config bug)
-    pull_op = as_pull(op) if cfg.direction != "push" else None
-    cnt, union = _host_round_counts(g, frontier, cfg)
-    cnt = np.asarray(cnt)
-    _note_host_transfer()              # THE per-round host sync point
-    nf = int(cnt[0])                                   # union size
-    active = cnt[-b:] > 0
-    if nf == 0:
-        out = ((labels if batched else labels[0]), None)
-        return out + (active,) if return_active else out
-    m_f = _counts_frontier_edges(cnt, plan)
-    direction = resolve_direction(cfg, nf, m_f, v, g.num_edges)
+    # the round's phases, as profiler spans (DESIGN.md section 11); each
+    # holds all of its host work, so that a device idle gap inside the
+    # round falls in one of them
+    with jax.profiler.TraceAnnotation("graph.counts"):
+        if not batched:
+            values, labels, frontier = (values[None], labels[None],
+                                        frontier[None])
+        b, v = labels.shape
+        plan = effective_plan(cfg)
+        # validate direction x operator up front (even when adaptive
+        # ends up resolving to push every round, a bad pairing is a
+        # config bug)
+        pull_op = as_pull(op) if cfg.direction != "push" else None
+        cnt, union = _host_round_counts(g, frontier, cfg)
+        cnt = np.asarray(cnt)
+        _note_host_transfer()          # THE per-round host sync point
+        nf = int(cnt[0])                               # union size
+        active = cnt[-b:] > 0
+        if nf == 0:
+            out = ((labels if batched else labels[0]), None)
+            return out + (active,) if return_active else out
 
-    ex = get_executor(cfg.executor)
-    stats = dict(frontier_size=nf, edges_twc=0, edges_lb=0,
-                 lb_invoked=False,
-                 tile_loads_twc=np.zeros(cfg.num_tiles, np.int64),
-                 tile_loads_lb=np.zeros(cfg.num_tiles, np.int64),
-                 frontier_per_query=cnt[-b:].astype(np.int64),
-                 direction=direction,
-                 frontier_edges=m_f,
-                 host_transfers=1) if collect_stats else None
-
-    if direction == "pull":
-        pe = _pull_enum(g, cfg)
-        labels = _run_plan_host(pe.rg, values, labels, frontier, plan,
-                                cfg, pull_op, ex, pe.bins, pe.lb, stats)
-    else:
-        fcap = next_bucket(nf)
-        fidx = compact(union, fcap)
-        deg, row_start, valid = _frontier_meta(g, fidx)
-        bins, lb = _assemble_bins(cnt, plan, cfg, fidx, deg, row_start,
-                                  valid, fcap, v)
-        labels = _run_plan_host(g, values, labels, frontier, plan, cfg,
-                                op, ex, bins, lb, stats)
-    labels = labels if batched else labels[0]
+    with jax.profiler.TraceAnnotation("graph.plan"):
+        m_f = _counts_frontier_edges(cnt, plan)
+        direction = resolve_direction(cfg, nf, m_f, v, g.num_edges)
+        ex = get_executor(cfg.executor)
+        stats = dict(frontier_size=nf, edges_twc=0, edges_lb=0,
+                     lb_invoked=False,
+                     frontier_per_query=cnt[-b:].astype(np.int64),
+                     direction=direction,
+                     frontier_edges=m_f,
+                     host_transfers=1) if collect_stats else None
+        if direction == "pull":
+            pe = _pull_enum(g, cfg)
+            gr, rop, bins, lb = pe.rg, pull_op, pe.bins, pe.lb
+        else:
+            fcap = next_bucket(nf)
+            fidx = compact(union, fcap)
+            deg, row_start, valid = _frontier_meta(g, fidx)
+            bins, lb = _assemble_bins(cnt, plan, cfg, fidx, deg,
+                                      row_start, valid, fcap, v)
+            gr, rop = g, op
+    with jax.profiler.TraceAnnotation("graph.passes"):
+        labels = _run_plan_host(gr, values, labels, frontier, plan, cfg,
+                                rop, ex, bins, lb, stats)
+        labels = labels if batched else labels[0]
     out = (labels, RoundStats(**stats) if stats is not None else None)
     return out + (active,) if return_active else out
 
@@ -988,11 +1008,7 @@ def _relax_spmd_impl(g: Graph, values: jax.Array, labels: jax.Array,
     ``collect_stats=True`` and/or ``(..., dirty)`` with
     ``return_dirty=True`` — ``dirty`` is the jit-safe changed-label
     bitvector the master/mirror sync exchanges over (DESIGN.md
-    section 6).  ``tile_loads_twc`` reflects this mode's actual deal —
-    bin members spread over tiles in static capacity-V slot order — so
-    it is comparable across rounds/devices but not bit-identical to the
-    host round's bucketed-compacted deal; the LB-path loads use the
-    same balanced formula in both modes.
+    section 6).
 
     Like :func:`relax`, accepts batched ``[B, V]`` labels/values/
     frontier (DESIGN.md section 7): the static-capacity enumeration,
@@ -1024,7 +1040,6 @@ def _relax_spmd_impl(g: Graph, values: jax.Array, labels: jax.Array,
     ex = get_executor(cfg.executor)
     plan = effective_plan(cfg)
     edges_twc = jnp.int32(0)
-    tl_twc = jnp.zeros((cfg.num_tiles,), jnp.int32)
 
     for spec in plan.bins:
         mask = spec.mask(deg, valid)
@@ -1055,11 +1070,9 @@ def _relax_spmd_impl(g: Graph, values: jax.Array, labels: jax.Array,
                 cond, body, (jnp.int32(0), labels))
         if collect_stats:
             edges_twc = edges_twc + jnp.sum(bdeg).astype(jnp.int32)
-            tl_twc = tl_twc + _tile_loads(bdeg, mask, cfg.num_tiles)
 
     edges_lb = jnp.int32(0)
     lb_invoked = jnp.asarray(False)
-    tl_lb = jnp.zeros((cfg.num_tiles,), jnp.int32)
     if plan.lb != "none":
         hmask = plan.lb_mask(deg, valid, cfg.threshold)
         n_huge = jnp.sum(hmask.astype(jnp.int32))
@@ -1073,14 +1086,12 @@ def _relax_spmd_impl(g: Graph, values: jax.Array, labels: jax.Array,
             new = ex.lb_jit(g, values, labels, frontier, hvidx, hdeg,
                             hrow, total, ecap, op, cfg.distribution,
                             cfg.num_tiles, cfg.lb_tile_edges)
-            return new, total.astype(jnp.int32), \
-                _lb_tile_loads(total, cfg.num_tiles)
+            return new, total.astype(jnp.int32)
 
         def skip_branch(labels):
-            return labels, jnp.int32(0), \
-                jnp.zeros((cfg.num_tiles,), jnp.int32)
+            return labels, jnp.int32(0)
 
-        labels, edges_lb, tl_lb = jax.lax.cond(
+        labels, edges_lb = jax.lax.cond(
             n_huge > 0, lb_branch, skip_branch, labels)
         lb_invoked = n_huge > 0
 
@@ -1090,7 +1101,6 @@ def _relax_spmd_impl(g: Graph, values: jax.Array, labels: jax.Array,
             frontier_size=jnp.sum(union.astype(jnp.int32)),
             edges_twc=edges_twc, edges_lb=edges_lb,
             lb_invoked=lb_invoked,
-            tile_loads_twc=tl_twc, tile_loads_lb=tl_lb,
             mirrors_synced=jnp.int32(0), bytes_synced=jnp.int32(0),
             frontier_per_query=jnp.sum(frontier.astype(jnp.int32),
                                        axis=1)),)
@@ -1164,8 +1174,7 @@ def relax_fused_round(g: Graph, rg: Optional[Graph],
     return labels_out, is_pull, nf, m_f, st
 
 
-def _fused_stats_init(max_rounds: int, b: int, num_tiles: int
-                      ) -> RoundStatsDev:
+def _fused_stats_init(max_rounds: int, b: int) -> RoundStatsDev:
     """Device-resident per-round stat buffers of a fused traversal:
     a :class:`RoundStatsDev` whose every leaf gained a leading
     ``[max_rounds]`` round axis, zero-filled."""
@@ -1174,8 +1183,6 @@ def _fused_stats_init(max_rounds: int, b: int, num_tiles: int
         frontier_size=z((max_rounds,)),
         edges_twc=z((max_rounds,)), edges_lb=z((max_rounds,)),
         lb_invoked=jnp.zeros((max_rounds,), bool),
-        tile_loads_twc=z((max_rounds, num_tiles)),
-        tile_loads_lb=z((max_rounds, num_tiles)),
         mirrors_synced=z((max_rounds,)), bytes_synced=z((max_rounds,)),
         bytes_wire=z((max_rounds,)),
         frontier_per_query=z((max_rounds, b)),
@@ -1194,7 +1201,7 @@ def _run_fused_loop(g: Graph, rg, emask, labels, frontier,
     the round index.  The loop condition probes the union frontier on
     device, so between dispatch and the caller's final fetch no value
     ever crosses to the host."""
-    st0 = (_fused_stats_init(max_rounds, labels.shape[0], cfg.num_tiles)
+    st0 = (_fused_stats_init(max_rounds, labels.shape[0])
            if collect_stats else None)
 
     def cond(carry):
