@@ -20,9 +20,11 @@ tiles; warps/threads -> VPU lanes; atomicMin -> XLA scatter-min;
 the inspector -> a vector reduction + host/`lax.cond` dispatch; cyclic
 vs blocked edge deal -> lane-major contiguous vs strided edge-id order.
 
-Architecture (DESIGN.md section 3): a strategy is *planned* once —
+Architecture (DESIGN.md section 3): a strategy is *planned* —
 ``make_plan`` turns a :class:`BalancerConfig` into a :class:`RoundPlan`
-of degree bins plus an LB mode — and *executed* by one of two
+of degree bins plus an LB mode for the static-capacity rounds, and
+``host_plan`` into the host round's, which for ``alb`` splits the bins
+below the threshold into a finer ladder — and *executed* by one of two
 interchangeable executor pairs from the registry:
 
 * ``xla``    — pure jnp building blocks (``_bin_pass`` / ``_lb_pass``),
@@ -38,9 +40,9 @@ Each :class:`ExecutorPair` exposes every path twice:
   traced chunk index, ``lax.cond`` inspector — used by ``relax_spmd``
   inside ``shard_map`` for the distributed (Gluon-analog) runtime.
 
-Both rounds therefore run the *same* planner and the *same* executor
-implementations; ``use_pallas=True`` routes the hot mapping loops
-through the Pallas kernels in either mode.
+Both rounds therefore run the *same* executor implementations;
+``use_pallas=True`` routes the hot mapping loops through the Pallas
+kernels in either mode.
 
 Batched multi-source queries (DESIGN.md section 7): ``relax`` and
 ``relax_spmd`` also accept ``labels[B, V]`` / ``values[B, V]`` /
@@ -142,19 +144,24 @@ class BalancerConfig:
 
 
 # ---------------------------------------------------------------------------
-# round planner — the ONE place a strategy is defined (both round modes
-# consume the same plan)
+# round planner — the ONE place a strategy is defined: make_plan's bins
+# for the static-capacity rounds, host_plan's for the host-driven round
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
 class BinSpec:
     """One degree bin of the vertex-binned (TWC-analog) path.
 
+    Both plans of a strategy are made of them: :func:`make_plan`'s (the
+    static-capacity rounds) and :func:`host_plan`'s (the host-driven
+    round).
+
     A frontier vertex lands in the bin when ``lo < deg`` and (if ``hi``
     is set) ``deg <= hi``.  ``cap`` is a static upper bound on the
     degree of any member (used by the fully-jit round to fix the pass
     count); ``cap=None`` marks a genuinely unbounded bin, driven by a
-    data-dependent number of width-``width`` passes.
+    data-dependent number of width-``width`` passes.  A pass over the
+    bin issues ``width`` slots per member, used or not.
     """
     name: str
     width: int
@@ -205,8 +212,17 @@ class RoundPlan:
 
 def make_plan(cfg: BalancerConfig) -> RoundPlan:
     """Turn a config into the degree bins + LB mode of its strategy —
-    the ONE place a strategy is defined (both round modes consume the
-    same plan)."""
+    the paper's bins, which the static-capacity rounds run
+    (``relax_spmd``, ``run_fused``, the Gluon rounds and the serving
+    engine's fused loop).
+
+    Those rounds run every bin at capacity V, so each bin costs a
+    ``[V, width]`` pass whatever its membership, and few wide bins are
+    cheapest there: ``alb``'s three (small/medium/large, widths
+    8/128/1024 by default) sum to a width of 1,160 per round.  The
+    host-driven round gathers each bin at a capacity sized to its
+    members and runs :func:`host_plan`'s finer ladder instead
+    (DESIGN.md section 3)."""
     s, sw, mw, lw, th = (cfg.strategy, cfg.small_width, cfg.medium_width,
                          cfg.large_width, cfg.threshold)
     d = cfg.direction
@@ -228,7 +244,7 @@ def make_plan(cfg: BalancerConfig) -> RoundPlan:
 
 
 def effective_plan(cfg: BalancerConfig) -> RoundPlan:
-    """The plan a round actually executes.
+    """The plan a static-capacity round actually executes.
 
     Normally :func:`make_plan`'s strategy bins; under the
     ``merge_path`` backend the plan collapses to ``RoundPlan((),
@@ -241,6 +257,45 @@ def effective_plan(cfg: BalancerConfig) -> RoundPlan:
     if cfg.executor == "merge_path":
         return RoundPlan((), "all", cfg.direction)
     return make_plan(cfg)
+
+
+def _ladder_bins(small_width: int, threshold: int) -> tuple:
+    """``alb``'s bins below ``threshold`` as a doubling ladder: (0,
+    small_width] at width ``small_width``, then (w/2, w] at width w,
+    doubling w until a bin reaches ``threshold - 1``, where the last
+    one ends.  Every bin takes one pass (``cap = width``), above the
+    first rung a member fills more than half of its row, and the bins
+    stay disjoint with each other and with the huge bin (``deg >=
+    threshold``)."""
+    top = threshold - 1
+    hi = min(small_width, top)
+    bins = [BinSpec(f"w{small_width}", small_width, 0, hi, small_width)]
+    width = small_width
+    while hi < top:
+        lo, width = width, 2 * width
+        hi = min(width, top)
+        bins.append(BinSpec(f"w{width}", width, lo, hi, width))
+    return tuple(bins)
+
+
+def host_plan(cfg: BalancerConfig) -> RoundPlan:
+    """The plan the host-driven round (:func:`relax`) executes.
+
+    :func:`effective_plan`'s, apart from ``alb``: there the host round
+    runs the degree ladder of :func:`_ladder_bins` (by default widths
+    8, 16, ..., 1024) in place of the paper's three bins.  The host
+    round gathers each bin at the power-of-two bucket above its member
+    count, so a bin's slots are its members times its width: above the
+    first rung a member fills more than half of its row, where a
+    degree-129 member of the paper's large bin (width 1024) fills 13%.
+    The static-capacity rounds, which pay ``[V, width]`` per bin, keep
+    :func:`make_plan`'s bins.  The huge bin and the LB pass are the
+    same in both plans."""
+    plan = effective_plan(cfg)
+    if plan.lb != "huge":          # not alb, or merge_path's LB-all plan
+        return plan
+    return RoundPlan(_ladder_bins(cfg.small_width, cfg.threshold),
+                     plan.lb, plan.direction)
 
 
 def resolve_direction(cfg: BalancerConfig, frontier_size: int,
@@ -717,7 +772,8 @@ def _host_round_counts(g: Graph, frontier: jax.Array, cfg: BalancerConfig):
     frontier count and inspector sums).
 
     Layout: ``[union_frontier_count,
-               (bin_count, bin_max_deg, bin_edge_sum) per plan bin...,
+               (bin_count, bin_max_deg, bin_edge_sum) per bin of
+               :func:`host_plan`...,
                huge_count, huge_edge_sum (when the plan has an LB path),
                per-query frontier counts (B entries, batched input only)]``
 
@@ -729,7 +785,7 @@ def _host_round_counts(g: Graph, frontier: jax.Array, cfg: BalancerConfig):
     """
     deg = g.row_ptr[1:] - g.row_ptr[:-1]
     union = union_frontier(frontier)
-    plan = effective_plan(cfg)
+    plan = host_plan(cfg)
     vals = [count(union)]
     for spec in plan.bins:
         m = spec.mask(deg, union)
@@ -750,8 +806,9 @@ def _counts_frontier_edges(cnt: np.ndarray, plan: RoundPlan) -> int:
     """Union-frontier out-edge total, reassembled from the fused host
     count layout of :func:`_host_round_counts` (per-bin edge sums plus
     the LB-path sum) — the ``m_f`` input of :func:`resolve_direction`.
-    The plan's bins and LB mask partition the frontier's edges for
-    every strategy, so the sum is exact."""
+    ``plan`` is the :func:`host_plan` the counts were taken for; its
+    bins and LB mask partition the frontier's edges for every
+    strategy, so the sum is exact."""
     k, total = 1, 0
     for _ in plan.bins:
         total += int(cnt[k + 2])
@@ -764,12 +821,13 @@ def _counts_frontier_edges(cnt: np.ndarray, plan: RoundPlan) -> int:
 class _PullEnum(NamedTuple):
     """Frontier-independent pull-side enumeration of one (graph, plan):
     the reverse CSR plus pre-gathered bin/LB member arrays over every
-    vertex with incoming edges, binned by IN-degree (DESIGN.md
-    section 9).  A pull round gathers at each in-edge's source, so its
-    work set never depends on the frontier — it is built once per
-    graph x plan (one blocking transfer, amortized) and cached on the
-    Graph object, keeping pull rounds free of per-round device syncs
-    and per-round gather dispatches."""
+    vertex with incoming edges, binned by IN-degree into the bins of
+    :func:`host_plan` (DESIGN.md section 9).  A pull round gathers at
+    each in-edge's source, so its work set never depends on the
+    frontier — it is built once per graph x plan (one blocking
+    transfer, amortized) and cached on the Graph object, keeping pull
+    rounds free of per-round device syncs and per-round gather
+    dispatches."""
     rg: Graph
     emask: jax.Array     # bool[V]: in-degree > 0 (the enumeration set)
     bins: tuple          # per plan bin: None | (max_d, edge_sum,
@@ -778,15 +836,14 @@ class _PullEnum(NamedTuple):
 
 
 def _pull_plan_key(cfg: BalancerConfig) -> tuple:
-    """The cfg fields a pull enumeration depends on (the plan's bins +
-    LB mask); direction/deal fields deliberately excluded so
-    push/adaptive variants share one cache entry.  The xla and pallas
-    backends share entries too (same plan), but ``merge_path`` replaces
-    the plan (no bins, LB = all — :func:`effective_plan`), so its
-    enumeration is keyed separately."""
-    return (cfg.strategy, cfg.threshold, cfg.small_width,
-            cfg.medium_width, cfg.large_width,
-            cfg.executor == "merge_path")
+    """What a pull enumeration depends on: the bins and LB mode of the
+    config's :func:`host_plan`, and the threshold of its LB mask.  The
+    direction and deal fields are left out, so push/adaptive variants
+    share one cache entry; so do the xla and pallas backends (same
+    plan), while ``merge_path``'s plan (no bins, LB = all —
+    :func:`effective_plan`) keys its own."""
+    plan = host_plan(cfg)
+    return (plan.bins, plan.lb, cfg.threshold)
 
 
 @partial(jax.jit, static_argnames=("plan", "threshold"))
@@ -803,9 +860,10 @@ def _plan_masks(deg, valid, plan: RoundPlan, threshold: int):
 def _assemble_bins(cnt: np.ndarray, plan: RoundPlan,
                    cfg: BalancerConfig, fidx, deg, row_start, valid,
                    fcap: int, v: int):
-    """Gather the bin / LB member arrays named by the fused host count
-    vector (the :func:`_host_round_counts` layout: per-bin triplets,
-    then the inspector pair).  Returns ``(bins, lb)`` in the
+    """Gather the bin / LB member arrays of ``plan`` (the config's
+    :func:`host_plan`) named by the fused host count vector (the
+    :func:`_host_round_counts` layout: per-bin triplets, then the
+    inspector pair).  Returns ``(bins, lb)`` in the
     :func:`_run_plan_host` format — the ONE assembly shared by the push
     round (per round, over the frontier) and the cached pull
     enumeration (once per graph), so the count layout can never
@@ -842,7 +900,7 @@ def _build_pull_enum(g: Graph, cfg: BalancerConfig) -> _PullEnum:
     fcap = next_bucket(int(cnt[0]))
     fidx = compact(union, fcap)
     deg, row_start, valid = _frontier_meta(rg, fidx)
-    bins, lb = _assemble_bins(cnt, effective_plan(cfg), cfg, fidx, deg,
+    bins, lb = _assemble_bins(cnt, host_plan(cfg), cfg, fidx, deg,
                               row_start, valid, fcap, v)
     return _PullEnum(rg, emask, bins, lb)
 
@@ -946,7 +1004,7 @@ def relax(g: Graph, values: jax.Array, labels: jax.Array,
             values, labels, frontier = (values[None], labels[None],
                                         frontier[None])
         b, v = labels.shape
-        plan = effective_plan(cfg)
+        plan = host_plan(cfg)
         # validate direction x operator up front (even when adaptive
         # ends up resolving to push every round, a bad pairing is a
         # config bug)

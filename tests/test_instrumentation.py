@@ -19,9 +19,10 @@ from repro.core.graph import INF
 
 SLOT_COUNTERS = ("bin_slots", "bin_edges", "lb_slots", "lb_edges")
 
-# bins: small 0 < d <= 2 (width 2), medium 2 < d <= 4 (width 4), large
-# 4 < d <= 19 (width 8), huge d >= 20 (the LB pass); 48 tiles, so the
-# LB pass rounds its 32-id bucket up to 48 ids
+# the host round's ladder: 0 < d <= 2 (width 2), 2 < d <= 4 (width 4),
+# 4 < d <= 8 (width 8), 8 < d <= 16 (width 16), 16 < d <= 19 (width
+# 32), one pass each; huge d >= 20 (the LB pass); 48 tiles, so the LB
+# pass rounds its 32-id bucket up to 48 ids
 CFG = BalancerConfig(strategy="alb", threshold=20, small_width=2,
                      medium_width=4, large_width=8, num_tiles=48,
                      lb_tile_edges=16)
@@ -56,12 +57,13 @@ def _one_round(g, direction):
             host_transfer_count() - t0)
 
 
-# per bin: bucket(members) x width x passes; buckets are at least 64
-#   push, by out-degree: small {0, 1} 64x2, medium {2} 64x4, large {3}
-#     2 passes of 64x8 = 1408 slots for 1 + 2 + 3 + 11 = 17 edges; LB
-#     {4}: 25 edges, bucket 32, rounded to 48 ids
-#   pull, by in-degree over every vertex with in-edges: small (38
-#     vertices, 39 edges) 64x2, medium {10} 64x4, large {6} 2 x 64x8 =
+# per bin: bucket(members) x width; buckets are at least 64, empty bins
+# issue nothing
+#   push, by out-degree: (0,2] {0, 1} 64x2, (2,4] {2} 64x4, (8,16] {3}
+#     64x16: 128 + 256 + 1024 = 1408 slots for 1 + 2 + 3 + 11 = 17
+#     edges; LB {4}: 25 edges, bucket 32, rounded to 48 ids
+#   pull, by in-degree over every vertex with in-edges: (0,2] (38
+#     vertices, 39 edges) 64x2, (2,4] {10} 64x4, (8,16] {6} 64x16 =
 #     1408 slots for 39 + 3 + 10 = 52 edges; LB {5}: 24 edges, 48 ids
 @pytest.mark.parametrize("direction,want", [
     ("push", {"bin_slots": 1408, "bin_edges": 17, "lb_slots": 48,

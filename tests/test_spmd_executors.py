@@ -15,7 +15,7 @@ import pytest
 
 from repro.core import graph as G
 from repro.core.balancer import (BalancerConfig, RoundStats, relax,
-                                 relax_spmd, make_plan)
+                                 relax_spmd, make_plan, host_plan)
 from repro.core.frontier import single_source
 from repro.core import operators as ops
 from repro.core import gluon
@@ -274,7 +274,11 @@ def test_host_round_counts_layout():
     cnt, union = _host_round_counts(g, frontier, cfg)
     cnt = np.asarray(cnt)
     np.testing.assert_array_equal(np.asarray(union), np.asarray(frontier))
-    plan = make_plan(cfg)
+    # one count, a triplet per bin of the host plan, the inspector pair:
+    # threshold 64 gives the ladder (0,8], (8,16], (16,32], (32,63],
+    # so 1 + 3 * 4 + 2 = 15 entries
+    plan = host_plan(cfg)
+    assert len(plan.bins) == 4
     assert cnt.shape == (1 + 3 * len(plan.bins) + 2,)
     deg = np.asarray(g.row_ptr[1:]) - np.asarray(g.row_ptr[:-1])
     f = np.asarray(frontier)

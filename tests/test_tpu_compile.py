@@ -72,9 +72,13 @@ def _spec(sharding, shape, dtype=jnp.int32):
 # path: the huge bin (alb) or the whole frontier (merge_path)
 _TWC_BINS = [("small", 1 << 21, 8), ("medium", 1 << 19, 128),
              ("large", 1 << 17, 1024)]
+# the host round's alb ladder adds the rungs between them (host_plan)
+_LADDER_RUNGS = [("w16", 1 << 19, 16), ("w32", 1 << 18, 32),
+                 ("w64", 1 << 18, 64), ("w256", 1 << 17, 256),
+                 ("w512", 1 << 16, 512)]
 
 
-@pytest.mark.parametrize("name,members,width", _TWC_BINS)
+@pytest.mark.parametrize("name,members,width", _TWC_BINS + _LADDER_RUNGS)
 def test_twc_bin_map_compiles(one_chip, name, members, width):
     vec = _spec(one_chip, (members,))
     text = _compile(twc_gather.twc_bin_map.lower(
@@ -115,6 +119,20 @@ def test_xla_host_passes_compile(one_chip, batch):
                               _spec(one_chip, ()), 1 << 25,
                               ops.SSSP_RELAX, CFG.distribution,
                               CFG.num_tiles, CFG.lb_tile_edges))
+
+
+@pytest.mark.parametrize("name,members,width", _LADDER_RUNGS)
+def test_xla_ladder_bin_passes_compile(one_chip, name, members, width):
+    """The xla bin pass at the ladder's narrower widths, whose ``[N,
+    W]`` tiles XLA pads to 128 lanes for the element-wise work."""
+    g = Graph(_spec(one_chip, (V + 1,)), _spec(one_chip, (E,)),
+              _spec(one_chip, (E,)))
+    labels = _spec(one_chip, (1, V))
+    fmask = _spec(one_chip, (1, V), jnp.bool_)
+    bins = _spec(one_chip, (members,))
+    _compile(get_executor("xla").bin_host.lower(
+        g, labels, labels, fmask, bins, bins, bins, width, ops.BFS_HOP,
+        0))
 
 
 # ---- CPU checks ------------------------------------------------------------
